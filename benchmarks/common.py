@@ -88,8 +88,8 @@ def sharded_collective_counts(
     Collectives only appear as HLO ops under ``shard_map`` (the vmap runner
     batches them into transposes), and forcing host devices must happen
     before jax initializes — so the lowering runs in a subprocess with
-    ``p`` forced host devices (the tests/test_distributed.py idiom).
-    Lowering only: nothing is compiled or executed.
+    ``p`` forced host devices (the tests/test_distributed.py idiom), pinned
+    to the CPU backend. Lowering only: nothing is compiled or executed.
 
     ``combos`` maps row name -> SortConfig override kwargs plus ``nv`` (the
     payload count). Returns ``{name: {"all_to_all": n, "all_gather": n}}``.
@@ -130,6 +130,8 @@ def sharded_collective_counts(
     )
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={p}"
+    # the child only lowers: it must never take the accelerator the parent holds
+    env["JAX_PLATFORMS"] = "cpu"
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.path.join(root, "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""

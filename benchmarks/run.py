@@ -18,6 +18,7 @@ import time
 
 from benchmarks import tables
 from benchmarks.common import emit, t_comp_per_cmp, write_json
+from repro.compile_cache import enable_compile_cache
 
 M = 1 << 20
 
@@ -37,6 +38,7 @@ def main() -> None:
         "fitted (g, L) cost report",
     )
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.full:
         from benchmarks import common

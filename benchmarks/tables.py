@@ -11,6 +11,8 @@ validated even though absolute CPU numbers differ by hardware.
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 import time
 from typing import Dict
 
@@ -694,10 +696,12 @@ def table_radix(n, p=16, repeats=4):
         "U64": rng.integers(-(2**62), 2**62, (p, n_p), dtype=np.int64),
         "zipf_skew": datagen.generate("zipf", p, n_p, seed=21),
     }
-    from jax.experimental import enable_x64
-
     for mix, xs in mixes.items():
-        scope = enable_x64 if xs.dtype == np.int64 else _null_scope
+        scope = (
+            functools.partial(jax.enable_x64, True)
+            if xs.dtype == np.int64
+            else contextlib.nullcontext
+        )
         with scope():
             x = jnp.asarray(xs)
 
@@ -734,16 +738,6 @@ def table_radix(n, p=16, repeats=4):
                  "served_by_sample": st_s.last_tier,
                  "complete": ok},
             )
-
-
-class _null_scope:
-    """No-op stand-in for ``enable_x64`` on 32-bit mixes."""
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
 
 
 def table_duplicate_handling_overhead(n, p=64):

@@ -34,7 +34,7 @@ variants, measured (not asserted) by the ``capacity`` benchmark table's
 the serving engine and the benchmark tables.
 
 The route stage's Ph5 exchange is *fused* by default
-(``SortConfig.exchange="fused"``): key + payload rows are byte-packed into
+(``SortConfig.exchange="fused"``): key + payload rows are word-packed into
 one send buffer so each data superstep issues exactly ONE collective
 regardless of payload count, and the Ph6 ``merge="tree"`` tail is
 payload-generic — rank positions are computed once on the keys and every
@@ -99,7 +99,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from . import merge as merge_mod
-from . import primitives as prim
 from . import routing, splitters
 from .bitonic import sort_bitonic_spmd
 from .local_sort import local_sort
@@ -309,7 +308,7 @@ class SortExecutor:
 
     All callables take the rng as raw ``jax.random.key_data`` (a (2,) uint32
     array) rather than a typed key: key data passes uniformly through jit
-    *and* ``shard_map`` in/out specs on the pinned jax 0.4.37.
+    *and* ``shard_map`` in/out specs.
     """
 
     def __init__(self) -> None:
@@ -411,9 +410,10 @@ class SortExecutor:
                 prep = prepare(xk[0], axis=mesh_axis, values=[v[0] for v in vk])
                 return jax.tree.map(lambda a: a[None], prep)
 
-            shmapped = prim.shard_map(
+            shmapped = jax.shard_map(
                 body,
                 mesh=mesh,
+                check_vma=False,
                 in_specs=(P(mesh_axis),) * (1 + n_values),
                 out_specs=self._prep_specs(pcfg, mesh_axis, n_values),
             )
@@ -445,9 +445,10 @@ class SortExecutor:
                     overflow[None],
                 )
 
-            shmapped = prim.shard_map(
+            shmapped = jax.shard_map(
                 body,
                 mesh=mesh,
+                check_vma=False,
                 in_specs=(
                     self._prep_specs(tier_cfg, mesh_axis, n_values),
                     P(),
@@ -488,9 +489,10 @@ class SortExecutor:
                     overflow[None],
                 )
 
-            shmapped = prim.shard_map(
+            shmapped = jax.shard_map(
                 body,
                 mesh=mesh,
+                check_vma=False,
                 in_specs=(P(),) + (P(mesh_axis),) * (1 + n_values),
                 out_specs=(
                     P(mesh_axis),

@@ -13,46 +13,12 @@ from __future__ import annotations
 
 from typing import Sequence
 
-import jax
 import jax.numpy as jnp
 from jax import lax
 
 
 def proc_id(axis: str) -> jnp.ndarray:
     return lax.axis_index(axis)
-
-
-def nprocs(axis: str) -> int:
-    """Static size of the named processor axis.
-
-    ``lax.axis_size`` only exists on newer JAX; on 0.4.x the portable idiom
-    is ``psum`` of a unit constant, which both vmap and shard_map constant-
-    fold to a Python int at trace time. Collectives that build permutation
-    tables prefer an explicitly threaded static ``p`` (see ``ppermute_shift``
-    / ``exchange_with``) so they never depend on this trace-time folding.
-    """
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` (replication checks off).
-
-    ``jax.shard_map(..., check_vma=...)`` on newer JAX; the pinned 0.4.37
-    only has ``jax.experimental.shard_map.shard_map(..., check_rep=...)``.
-    Every real-device entry point (core/api.py, models/moe.py) goes through
-    this wrapper so the collective layer has exactly one version seam.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
 
 
 def broadcast_from(x: jnp.ndarray, src: int, axis: str) -> jnp.ndarray:
@@ -86,7 +52,7 @@ def ppermute_shift(x, axis: str, shift: int = 1, *, p: int | None = None):
     ``p`` is the static axis size; callers thread it from their SortConfig
     (the permutation table must be built at trace time).
     """
-    p = nprocs(axis) if p is None else p
+    p = lax.axis_size(axis) if p is None else p
     perm = [(i, (i + shift) % p) for i in range(p)]
     if isinstance(x, (tuple, list)):
         return type(x)(lax.ppermute(v, axis, perm) for v in x)
@@ -95,7 +61,7 @@ def ppermute_shift(x, axis: str, shift: int = 1, *, p: int | None = None):
 
 def exchange_with(x, partner_xor: int, axis: str, *, p: int | None = None):
     """Pairwise exchange with the XOR partner (bitonic compare-split step)."""
-    p = nprocs(axis) if p is None else p
+    p = lax.axis_size(axis) if p is None else p
     perm = [(i, i ^ partner_xor) for i in range(p)]
     if isinstance(x, (tuple, list)):
         return type(x)(lax.ppermute(v, axis, perm) for v in x)

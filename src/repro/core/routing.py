@@ -22,8 +22,8 @@ Fused exchange (``SortConfig.exchange``)
 ----------------------------------------
 The paper's Ph5 is ONE h-relation superstep; a key-value sort must not pay
 one collective per array. Under ``exchange="fused"`` (the default) the key
-and every payload row are bitcast to bytes and concatenated along the
-trailing dim into a single send buffer, so each data superstep issues
+and every payload row are bitcast to uint32 words and concatenated along
+the trailing dim into a single send buffer, so each data superstep issues
 exactly ONE collective regardless of payload count — one ``all_to_all`` for
 ``a2a_dense`` (plus the tiny (p,)-word Ph4 count bookkeeping superstep), one
 ``all_gather`` for ``allgather`` (plus the boundary bookkeeping gather), and
@@ -32,7 +32,7 @@ boundary vector share the packed buffer). The buffer is unpacked (bitcast
 back) after delivery; packing is bit-exact, so the fused path is
 byte-identical to ``exchange="per_array"`` (the one-collective-per-array
 layout, kept as the measured baseline — see the ``hotpath`` benchmark
-table). The pack/unpack helpers (:func:`pack_bytes` / :func:`unpack_bytes`)
+table). The pack/unpack helpers (:func:`pack_words` / :func:`unpack_words`)
 are shared with the MoE EP dispatch (models/moe.py).
 
 All schedules preserve source order: the receive buffer is compacted by
@@ -88,73 +88,97 @@ def _pad_value_for(arr: jnp.ndarray) -> jnp.ndarray:
     return jnp.zeros((), arr.dtype)
 
 
-# ------------------------------------------------------ fused byte packing
-def _nbytes(dtype, trail) -> int:
-    return int(np.prod(trail, dtype=np.int64)) * jnp.dtype(dtype).itemsize
+# ------------------------------------------------------ fused word packing
+def _unsigned(a: jnp.ndarray) -> jnp.ndarray:
+    """The bits of a sub-word array as the unsigned type of its width."""
+    if a.dtype == jnp.bool_:
+        return a.astype(jnp.uint8)
+    return lax.bitcast_convert_type(a, jnp.dtype(f"uint{8 * a.dtype.itemsize}"))
 
 
-def pack_bytes(
-    arrs: Sequence[jnp.ndarray], lead: int = 2
-) -> Tuple[jnp.ndarray, tuple]:
-    """Bitcast arrays sharing ``lead`` leading dims into ONE uint8 buffer.
+def _to_words(a: jnp.ndarray, lead: int) -> jnp.ndarray:
+    """uint32 words of ``a``: its trailing dims flattened to one word axis.
 
-    Each (l0, .., l_{lead-1}, ...) array contributes its trailing dims as a
-    flat byte run along a new last axis; the concatenation is the single
-    send buffer of a fused collective. Returns ``(buffer, metas)`` where
-    ``metas`` is the static recipe :func:`unpack_bytes` inverts bit-exactly.
+    A 64-bit array gives its low words, then its high words. A sub-word
+    array is cut into ``4 // itemsize`` contiguous chunks of its trailing
+    elements (zero-padded), and chunk ``i`` fills bits ``i·8·itemsize`` up
+    of each word. No array ever takes a byte view.
     """
-    parts, metas = [], []
-    for a in arrs:
-        b = lax.bitcast_convert_type(a, jnp.uint8)
-        parts.append(b.reshape(a.shape[:lead] + (-1,)))
-        metas.append((a.dtype, a.shape[lead:]))
-    return jnp.concatenate(parts, axis=-1), tuple(metas)
+    head, s = a.shape[:lead], a.dtype.itemsize
+    if s == 4:
+        return lax.bitcast_convert_type(a, jnp.uint32).reshape(head + (-1,))
+    if s == 8:
+        u = lax.bitcast_convert_type(a, jnp.uint64).reshape(head + (-1,))
+        return jnp.concatenate(
+            [u.astype(jnp.uint32), (u >> 32).astype(jnp.uint32)], axis=-1
+        )
+    r = 4 // s
+    u = _unsigned(a).reshape(head + (-1,)).astype(jnp.uint32)
+    c = -(-u.shape[-1] // r)
+    u = jnp.pad(u, [(0, 0)] * lead + [(0, c * r - u.shape[-1])])
+    w = u[..., :c]
+    for i in range(1, r):
+        w = w | (u[..., i * c : (i + 1) * c] << (8 * s * i))
+    return w
 
 
-def unpack_bytes(
-    buf: jnp.ndarray, metas: tuple, lead: int = 2
+def _from_words(w: jnp.ndarray, dtype, trail: tuple, lead: int) -> jnp.ndarray:
+    """Invert :func:`_to_words` for one array."""
+    shape, s = w.shape[:lead] + tuple(trail), dtype.itemsize
+    if s == 4:
+        return lax.bitcast_convert_type(w, dtype).reshape(shape)
+    if s == 8:
+        lo, hi = jnp.split(w, 2, axis=-1)
+        u = lo.astype(jnp.uint64) | (hi.astype(jnp.uint64) << 32)
+        return lax.bitcast_convert_type(u, dtype).reshape(shape)
+    bits = 8 * s
+    u = jnp.concatenate(
+        [(w >> (bits * i)) & ((1 << bits) - 1) for i in range(4 // s)], axis=-1
+    )
+    u = u[..., : int(np.prod(trail, dtype=np.int64))].astype(f"uint{bits}")
+    if dtype == jnp.bool_:
+        return (u != 0).reshape(shape)
+    return lax.bitcast_convert_type(u, dtype).reshape(shape)
+
+
+def _word_count(dtype, trail) -> int:
+    """Words one leading index of a (dtype, trail) array packs into."""
+    m, s = int(np.prod(trail, dtype=np.int64)), jnp.dtype(dtype).itemsize
+    return m * s // 4 if s >= 4 else -(-m // (4 // s))
+
+
+def pack_words(
+    arrs: Sequence[jnp.ndarray], lead: int
+) -> Tuple[jnp.ndarray, tuple]:
+    """Pack arrays sharing ``lead`` leading dims into ONE uint32 buffer.
+
+    Each array contributes its trailing dims as one run of uint32 words
+    along the last axis (:func:`_to_words`); the concatenation is the single
+    send buffer of a fused collective. Callers keep as leading dims only
+    the axis the collective splits (``lead=1``: an all_to_all over (p, w)
+    rows; ``lead=0``: a gathered or rotated block), so each array's words
+    stay one long contiguous run. A byte view, or one word per row, would
+    leave a minor axis a few elements wide, which the TPU pads to 128 lanes
+    (at 2^25 keys per chip one such ring buffer outgrew the chip's HBM).
+    Returns ``(buffer, metas)``, the static recipe :func:`unpack_words`
+    inverts bit-exactly.
+    """
+    parts = [_to_words(a, lead) for a in arrs]
+    metas = tuple((a.dtype, a.shape[lead:]) for a in arrs)
+    return jnp.concatenate(parts, axis=-1), metas
+
+
+def unpack_words(
+    buf: jnp.ndarray, metas: tuple, lead: int
 ) -> List[jnp.ndarray]:
-    """Invert :func:`pack_bytes` after delivery (bit-exact)."""
+    """Invert :func:`pack_words` after delivery (bit-exact). ``lead`` is the
+    buffer's leading dims, which a gather may have grown by one."""
     out, off = [], 0
-    head = buf.shape[:lead]
     for dtype, trail in metas:
         dtype = jnp.dtype(dtype)
-        nb = _nbytes(dtype, trail)
-        b = buf[..., off : off + nb]
-        off += nb
-        shape = head + tuple(trail)
-        if dtype.itemsize > 1:
-            shape = shape + (dtype.itemsize,)
-        out.append(lax.bitcast_convert_type(b.reshape(shape), dtype))
-    return out
-
-
-def pack_bytes_flat(arrs: Sequence[jnp.ndarray]) -> Tuple[jnp.ndarray, tuple]:
-    """Pack arbitrarily-shaped arrays into one flat uint8 vector.
-
-    The ring schedule's visitor block (local run + payloads + the (p+1,)
-    boundary vector) has mixed shapes; a flat byte vector lets the whole
-    block rotate in ONE ``ppermute`` per superstep.
-    """
-    parts, metas = [], []
-    for a in arrs:
-        parts.append(lax.bitcast_convert_type(a, jnp.uint8).reshape(-1))
-        metas.append((a.dtype, a.shape))
-    return jnp.concatenate(parts), tuple(metas)
-
-
-def unpack_bytes_flat(vec: jnp.ndarray, metas: tuple) -> List[jnp.ndarray]:
-    """Invert :func:`pack_bytes_flat` (bit-exact)."""
-    out, off = [], 0
-    for dtype, shape in metas:
-        dtype = jnp.dtype(dtype)
-        nb = _nbytes(dtype, shape)
-        b = vec[off : off + nb]
-        off += nb
-        full = tuple(shape)
-        if dtype.itemsize > 1:
-            full = full + (dtype.itemsize,)
-        out.append(lax.bitcast_convert_type(b.reshape(full), dtype))
+        k = _word_count(dtype, trail)
+        out.append(_from_words(buf[..., off : off + k], dtype, trail, lead))
+        off += k
     return out
 
 
@@ -168,9 +192,10 @@ def packed_row_bytes(key_dtype, value_dtypes=()) -> int:
     """Bytes one routed row carries in the fused exchange (key + payloads).
 
     Pure host math for the tracer: the fused Ph5 collective moves
-    byte-packed (key, payload...) rows, so a traced h-relation's byte
-    volume is ``counts × packed_row_bytes`` and its BSP h (32-bit words,
-    the paper's unit) is that over 4.
+    word-packed (key, payload...) runs, so a traced h-relation's byte
+    volume is ``counts × packed_row_bytes`` (up to the padding of a
+    sub-word run to whole words) and its BSP h (32-bit words, the paper's
+    unit) is that over 4.
     """
     return int(sum(np.dtype(d).itemsize for d in (key_dtype, *value_dtypes)))
 
@@ -227,8 +252,8 @@ def _segment_rows(
 def _all_to_all_rows(rows: List[jnp.ndarray], cfg: SortConfig, axis: str):
     """Deliver (p, w, ...) rows: ONE fused all_to_all, or one per array."""
     if cfg.exchange == "fused" and len(rows) > 1:
-        buf, metas = pack_bytes(rows, lead=2)
-        return unpack_bytes(lax.all_to_all(buf, axis, 0, 0), metas, lead=2)
+        buf, metas = pack_words(rows, lead=1)
+        return unpack_words(lax.all_to_all(buf, axis, 0, 0), metas, lead=1)
     return [lax.all_to_all(r, axis, 0, 0) for r in rows]
 
 
@@ -271,8 +296,8 @@ def recv_rows(
         idx = jnp.clip(starts[:, None] + t, 0, n_p - 1)
         valid = t < rcounts[:, None]
         if cfg.exchange == "fused" and len(arrs) > 1:
-            buf, metas = pack_bytes(arrs, lead=1)
-            gathered = unpack_bytes(lax.all_gather(buf, axis), metas, lead=2)
+            buf, metas = pack_words(arrs, lead=0)
+            gathered = unpack_words(lax.all_gather(buf, axis), metas, lead=1)
         else:
             gathered = [lax.all_gather(a, axis) for a in arrs]  # (p, n_p, ...)
         rows = []
@@ -390,7 +415,7 @@ def _route_ring(x_sorted, boundaries, cfg, axis, values, sent):
     """p-1 ppermute supersteps; visitor block = one local run + boundaries.
 
     Under ``exchange="fused"`` the whole visitor block (keys, payloads AND
-    the boundary vector) rotates as one packed byte vector — one collective
+    the boundary vector) rotates as one packed word vector — one collective
     per superstep regardless of payload count.
     """
     p, cap = cfg.p, cfg.n_max
@@ -423,9 +448,9 @@ def _route_ring(x_sorted, boundaries, cfg, axis, values, sent):
         ]
         if r != p - 1:
             if cfg.exchange == "fused":
-                vec, metas = pack_bytes_flat(list(vis_arrs) + [vis_b])
+                vec, metas = pack_words(list(vis_arrs) + [vis_b], lead=0)
                 vec = prim.ppermute_shift(vec, axis, 1, p=p)
-                *vis_list, vis_b = unpack_bytes_flat(vec, metas)
+                *vis_list, vis_b = unpack_words(vec, metas, lead=0)
                 vis_arrs = tuple(vis_list)
             else:
                 vis_arrs = prim.ppermute_shift(vis_arrs, axis, 1, p=p)

@@ -64,12 +64,12 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from .api import (
     InFlightSort,
@@ -355,7 +355,7 @@ def segmented_sort_launch(
     # in-flight sort and is re-entered when ``wait`` escalates. Single-
     # segment batches carry raw int32 keys and stay in native 32-bit mode.
     scope = (
-        enable_x64
+        functools.partial(jax.enable_x64, True)
         if packed.comp.dtype == np.int64
         else contextlib.nullcontext
     )

@@ -30,7 +30,7 @@ share a destination (stability is preserved through the source-ordered
 exchange). The boundaries of the sorted run are then a vectorised
 ``searchsorted`` — exactly the Ph4 shape the shared Ph5/Ph6 tail
 (:func:`routing.route_and_merge`) consumes, so the radix route rides the
-same fused byte-packed ``a2a_dense`` exchange and merge tail as the sample
+same fused word-packed ``a2a_dense`` exchange and merge tail as the sample
 route. Radix buckets arrive *disjoint* in key range, so the merge tail only
 ever interleaves equal-bucket runs — per-bucket local passes, never a
 global fix-up.

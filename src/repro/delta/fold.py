@@ -48,7 +48,6 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro import obs
 from repro.core import TierStats
@@ -61,6 +60,9 @@ from repro.core.api import (
 from repro.core.merge import _rank_merge_two
 from repro.core.segmented import SegmentedResult, _pow2_n_per_proc, contiguous_lane_sizes
 from repro.core.types import SortConfig, round_up, sentinel_for
+
+#: zero-argument scope factory for the int64 composites' device entries
+enable_x64 = functools.partial(jax.enable_x64, True)
 
 #: low bits of the fold composite holding the original position; the
 #: (biased) int32 key sits above. 31 + 32 bits keeps the composite inside

@@ -2,9 +2,9 @@
 
 The paper's Ph2 hot loop (85-90% of T3D runtime) is a scalar quicksort. The
 TPU-native analogue is a *sorting network over full vector registers*: every
-compare-exchange stage is a reshape + `jnp.where` on an (rows, width) VMEM
-tile, so the VPU processes 8×128 lanes per cycle with zero data-dependent
-control flow. Work is Θ(n lg² n) vs quicksort's Θ(n lg n) — the standard TPU
+compare-exchange stage is two lane rotations + `jnp.where` on an
+(rows, width) VMEM tile, so the VPU processes 8×128 lanes per cycle with
+zero data-dependent control flow. Work is Θ(n lg² n) vs quicksort's Θ(n lg n) — the standard TPU
 trade (DESIGN.md §7): the lg(n)/2 work inflation is paid for by lane
 parallelism and the absence of branches.
 
@@ -12,9 +12,12 @@ Layout: width must be a power of two (callers pad with the dtype sentinel —
 `ops.py` handles this) and ≥ 128 so the lane dimension stays MXU/VPU aligned.
 The row dimension batches independent sorts (grid over row blocks).
 
-The compare-exchange pairing `i ↔ i^j` is realized *without gathers* by
-reshaping to (rows, width/2j, 2, j): partners sit in adjacent sublane groups,
-and the per-group direction bit ((start & k) == 0) broadcasts along lanes.
+The compare-exchange pairing `i ↔ i^j` is realized *without gathers* and
+without reshaping the lane axis (Mosaic cannot split it): each lane fetches
+its partner with two lane rotations, ``x[i + j]`` where the lane index has
+bit j clear ("low" half of its pair) and ``x[i - j]`` where it is set. The
+direction bit ``(i & k) == 0`` comes from the same lane iota. These network
+functions use ``pltpu.roll`` and so run only inside a Pallas kernel.
 """
 from __future__ import annotations
 
@@ -23,68 +26,74 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _stage(x: jnp.ndarray, k: int, j: int) -> jnp.ndarray:
-    """One compare-exchange substage (partner = index XOR j, region size k)."""
-    r, w = x.shape
-    g = w // (2 * j)
-    x4 = x.reshape(r, g, 2, j)
-    a, b = x4[:, :, 0, :], x4[:, :, 1, :]
-    asc = (((jnp.arange(g) * 2 * j) & k) == 0)[None, :, None]
-    swap = jnp.where(asc, a > b, a < b)
-    na = jnp.where(swap, b, a)
-    nb = jnp.where(swap, a, b)
-    return jnp.stack([na, nb], axis=2).reshape(r, w)
+def _stage(lane, k: int, j, keys, *vals):
+    """One compare-exchange substage (partner = lane XOR j, region size k).
+
+    Swaps exactly when the (lower, upper) pair is out of order for its
+    region's direction — strict compares, so equal keys (and NaNs) stay put.
+    Every array in ``vals`` follows the key swaps. ``j`` may be traced.
+    """
+    w = keys.shape[-1]
+    low = (lane & j) == 0
+
+    def partner(a):
+        return jnp.where(low, pltpu.roll(a, w - j, 1), pltpu.roll(a, j, 1))
+
+    other = partner(keys)
+    # lower element of a descending pair, or upper element of an ascending one
+    flip = low ^ ((lane & k) == 0)
+    # boolean algebra, not a select: Mosaic cannot select between i1 vectors
+    swap = (flip & (keys < other)) | (~flip & (keys > other))
+    return (
+        jnp.where(swap, other, keys),
+        *(jnp.where(swap, partner(v), v) for v in vals),
+    )
 
 
-def _stage_kv(keys, vals, k: int, j: int):
-    r, w = keys.shape
-    g = w // (2 * j)
-    k4 = keys.reshape(r, g, 2, j)
-    v4 = vals.reshape(r, g, 2, j)
-    ka, kb = k4[:, :, 0, :], k4[:, :, 1, :]
-    va, vb = v4[:, :, 0, :], v4[:, :, 1, :]
-    asc = (((jnp.arange(g) * 2 * j) & k) == 0)[None, :, None]
-    swap = jnp.where(asc, ka > kb, ka < kb)
-    keys = jnp.stack([jnp.where(swap, kb, ka), jnp.where(swap, ka, kb)], 2).reshape(r, w)
-    vals = jnp.stack([jnp.where(swap, vb, va), jnp.where(swap, va, vb)], 2).reshape(r, w)
-    return keys, vals
+def _merge_steps(lane, k: int, h: int, arrays):
+    """Substages j = h, h/2, ..., 1 of region size k, as one loop.
+
+    A loop with a traced ``j`` instead of lg(h) unrolled stages keeps the
+    compile time and the kernel's live VMEM temporaries to one stage's worth.
+    """
+
+    def body(u, arrays):
+        return _stage(lane, k, jnp.int32(h) >> u, *arrays)
+
+    return jax.lax.fori_loop(0, h.bit_length(), body, arrays)
+
+
+def _sort_arrays(arrays):
+    keys = arrays[0]
+    lane, w = _lanes(keys), keys.shape[-1]
+    k = 2
+    while k <= w:
+        arrays = _merge_steps(lane, k, k // 2, arrays)
+        k *= 2
+    return arrays
+
+
+def _lanes(x):
+    return jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
 
 
 def sort_network(x: jnp.ndarray) -> jnp.ndarray:
     """Full bitonic sort along the last axis (width = power of two)."""
-    _, w = x.shape
-    k = 2
-    while k <= w:
-        j = k // 2
-        while j >= 1:
-            x = _stage(x, k, j)
-            j //= 2
-        k *= 2
-    return x
+    return _sort_arrays((x,))[0]
 
 
 def merge_network(x: jnp.ndarray) -> jnp.ndarray:
     """Bitonic *merge* of a bitonic row (ascending run ++ descending run)."""
-    _, w = x.shape
-    j = w // 2
-    while j >= 1:
-        x = _stage(x, 2 * w, j)  # k > w ⇒ every region ascending
-        j //= 2
-    return x
+    w = x.shape[-1]
+    # k > w ⇒ every region ascending
+    return _merge_steps(_lanes(x), 2 * w, w // 2, (x,))[0]
 
 
 def sort_network_kv(keys: jnp.ndarray, vals: jnp.ndarray):
-    _, w = keys.shape
-    k = 2
-    while k <= w:
-        j = k // 2
-        while j >= 1:
-            keys, vals = _stage_kv(keys, vals, k, j)
-            j //= 2
-        k *= 2
-    return keys, vals
+    return _sort_arrays((keys, vals))
 
 
 # ------------------------------------------------------------- pallas_call

@@ -101,13 +101,3 @@ def sort_kv(keys: jnp.ndarray, vals: jnp.ndarray):
         ko, vo = kernel.bitonic_sort_kv_tiles(kp, vp, interpret=_interpret())
         out = ko[:, :n], vo[:, :n]
     return (out[0][0], out[1][0]) if squeeze else out
-
-
-@jax.jit
-def merge_bitonic(x: jnp.ndarray) -> jnp.ndarray:
-    """Merge rows that are (ascending ++ descending) bitonic sequences."""
-    squeeze = x.ndim == 1
-    if squeeze:
-        x = x[None, :]
-    out = kernel.merge_network(x)  # pure jnp path; kernel variant in merge_path
-    return out[0] if squeeze else out

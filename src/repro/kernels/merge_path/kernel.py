@@ -1,9 +1,11 @@
 """Merge of two sorted runs — Pallas TPU kernel (the paper's Ph6 hot loop).
 
 Two sorted rows a, b of width W are merged by the *bitonic merge network*:
-``concat(a, reverse(b))`` is a bitonic sequence, so lg(2W)+1 compare-exchange
+``concat(a, reverse(b))`` is a bitonic sequence, so lg(2W) compare-exchange
 substages produce the sorted 2W row. Each substage is one full-width
-`jnp.where` on the VMEM tile — no gathers, no branches.
+`jnp.where` on the VMEM tile — no gathers, no branches. The reversal and the
+concatenation happen in the XLA wrapper (Mosaic has no lane reversal), so
+the kernel body is the merge network alone.
 
 This replaces the GPU "merge path" diagonal-partition idea (which needs
 per-thread binary searches — a scalar-unit pattern) with the TPU-idiomatic
@@ -17,22 +19,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.bitonic.kernel import _stage
+from repro.kernels.bitonic.kernel import merge_network
 
 
-def merge_rows(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """Merge sorted rows a,b (R, W) -> sorted (R, 2W) via bitonic merge."""
-    x = jnp.concatenate([a, b[:, ::-1]], axis=-1)  # bitonic rows
-    _, w2 = x.shape
-    j = w2 // 2
-    while j >= 1:
-        x = _stage(x, 2 * w2, j)  # k > width ⇒ ascending everywhere
-        j //= 2
-    return x
-
-
-def _merge_kernel(a_ref, b_ref, o_ref):
-    o_ref[...] = merge_rows(a_ref[...], b_ref[...])
+def _merge_kernel(x_ref, o_ref):
+    o_ref[...] = merge_network(x_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -41,20 +32,21 @@ def merge_sorted_tiles(
 ) -> jnp.ndarray:
     """Pairwise-merge rows of two (rows, width) sorted arrays.
 
-    VMEM per grid step = 4 · block_rows · width · itemsize (two inputs, one
-    double-width output); width must be a power of two ≥ 128.
+    VMEM per grid step = 4 · block_rows · width · itemsize (one double-width
+    bitonic input, one double-width output); width must be a power of two
+    ≥ 128.
     """
     rows, width = a.shape
     assert a.shape == b.shape
     assert width & (width - 1) == 0, "width must be a power of two"
+    x = jnp.concatenate([a, b[:, ::-1]], axis=-1)  # bitonic rows
     grid = (pl.cdiv(rows, block_rows),)
-    in_spec = pl.BlockSpec((block_rows, width), lambda i: (i, 0))
-    out_spec = pl.BlockSpec((block_rows, 2 * width), lambda i: (i, 0))
+    spec = pl.BlockSpec((block_rows, 2 * width), lambda i: (i, 0))
     return pl.pallas_call(
         _merge_kernel,
         grid=grid,
-        in_specs=[in_spec, in_spec],
-        out_specs=out_spec,
+        in_specs=[spec],
+        out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((rows, 2 * width), a.dtype),
         interpret=interpret,
-    )(a, b)
+    )(x)

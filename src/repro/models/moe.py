@@ -11,8 +11,8 @@ stability guarantee doing real work. Two paths:
   (data-like axes × model), each shard stable-sorts its token records by
   expert id (the paper's Ph2/step-9 "set formation"), computes per-dest
   segment boundaries, and routes through ONE ``lax.all_to_all`` (expert ids
-  and token rows byte-packed into a single send buffer — the fused
-  h-relation of ``core/routing.pack_bytes``) with a
+  and token rows word-packed into a single send buffer — the fused
+  h-relation of ``core/routing.pack_words``) with a
   capacity = (tokens/shard)·cf — the Claim 5.1-style w.h.p. bound with
   overflow *detected* and surfaced (``aux['overflow']``), never silently
   dropped. The reverse all_to_all + stable unsort is the combine.
@@ -54,7 +54,6 @@ from repro import obs as obs_mod
 from repro.configs.base import ArchConfig
 from repro.core import TierStats
 from repro.core import routing
-from repro.core.primitives import shard_map
 from repro.models.layers import _dense, dtype_of
 
 
@@ -186,9 +185,10 @@ def moe_tp_sharded(
 
     dp = _dp_spec(mesh_info, x.shape[0])
     seq = axis if x.shape[1] % mesh_info.model_size == 0 else None
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh_info.mesh,
+        check_vma=False,
         in_specs=(
             P(dp, seq, None),
             P(),
@@ -258,7 +258,7 @@ def moe_ep(
         )
 
         # paper steps 10-11: segment rows + ONE all_to_all (keys + payload
-        # byte-packed into a single send buffer — the fused h-relation, same
+        # word-packed into a single send buffer — the fused h-relation, same
         # helpers as core/routing's Ph5)
         tix = jnp.arange(pair_cap)[None, :]
         gidx = jnp.clip(bounds[:-1][:, None] + tix, 0, n - 1)
@@ -266,9 +266,9 @@ def moe_ep(
         rows_e = jnp.where(valid, sorted_e[gidx], -1)  # (p, pair_cap)
         sorted_tok = x2d[order // k]  # record i ↔ token order[i]//k
         rows_x = jnp.where(valid[..., None], sorted_tok[gidx], 0).astype(xl.dtype)
-        fused, metas = routing.pack_bytes([rows_e, rows_x], lead=2)
-        recv_e, recv_x = routing.unpack_bytes(
-            lax.all_to_all(fused, axis, 0, 0), metas, lead=2
+        fused, metas = routing.pack_words([rows_e, rows_x], lead=1)
+        recv_e, recv_x = routing.unpack_words(
+            lax.all_to_all(fused, axis, 0, 0), metas, lead=1
         )
 
         # local expert compute (masked over e_loc experts; e_loc ≤ 2 in all
@@ -312,9 +312,10 @@ def moe_ep(
 
     dp = _dp_spec(mesh_info, x.shape[0])
     seq = axis if x.shape[1] % mesh_info.model_size == 0 else None
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh_info.mesh,
+        check_vma=False,
         in_specs=(
             P(dp, seq, None),
             P(),
@@ -367,9 +368,10 @@ def moe_ep_counts(params: Dict, x: jnp.ndarray, cfg: ArchConfig, mesh_info: MoEM
         )[0]
     dp = _dp_spec(mesh_info, x.shape[0])
     seq = axis if x.shape[1] % mesh_info.model_size == 0 else None
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh_info.mesh,
+        check_vma=False,
         in_specs=(P(dp, seq, None), P()),
         out_specs=P(),
     )(x, params["router"])
@@ -551,9 +553,10 @@ def moe_ep_decode(params: Dict, x: jnp.ndarray, cfg: ArchConfig, mesh_info: MoEM
         return y.reshape(bl, sl, D), aux
 
     dp = _dp_spec(mesh_info, x.shape[0])
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh_info.mesh,
+        check_vma=False,
         in_specs=(
             P(dp, None, None),
             P(),

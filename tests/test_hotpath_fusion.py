@@ -13,6 +13,7 @@ Covers the three acceptance surfaces:
   payload count, counted on the real ``shard_map`` lowering in a subprocess
   with forced host devices (the vmap runner batches collectives away).
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -164,26 +165,49 @@ def test_segmented_composites_ride_tree_tail():
 
 @pytest.mark.fast
 def test_pack_bytes_roundtrip_mixed_dtypes():
-    """The fused-exchange packing is a bitcast: bit-exact for every dtype and
-    trailing shape the routing/MoE layers ship."""
+    """The fused-exchange packing is a bitcast into uint32 words: bit-exact
+    for every dtype and trailing shape the routing/MoE layers ship."""
     rng = np.random.default_rng(0)
     rows = [
         jnp.asarray(rng.integers(-(2**31), 2**31 - 1, (4, 16), dtype=np.int64).astype(np.int32)),
         jnp.asarray(rng.standard_normal((4, 16, 3)).astype(np.float32)),
         jnp.asarray(rng.standard_normal((4, 16)).astype(np.float32)).astype(jnp.bfloat16),
-        jnp.asarray(rng.integers(0, 127, (4, 16), dtype=np.int64).astype(np.int8)),
+        jnp.asarray(rng.integers(-128, 127, (4, 16, 5), dtype=np.int64).astype(np.int8)),
+        jnp.asarray(rng.standard_normal((4, 16, 7)).astype(np.float32)).astype(jnp.bfloat16),
+        jnp.asarray(rng.integers(0, 2, (4, 16), dtype=np.int64).astype(bool)),
     ]
-    buf, metas = routing.pack_bytes(rows, lead=2)
-    assert buf.dtype == jnp.uint8 and buf.shape[:2] == (4, 16)
-    out = routing.unpack_bytes(buf, metas, lead=2)
-    for o, r in zip(out, rows):
-        assert o.dtype == r.dtype and o.shape == r.shape
-        assert np.array_equal(np.asarray(o), np.asarray(r))
 
-    flat_in = [rows[0], jnp.arange(9, dtype=jnp.int32)]  # mixed shapes
-    vec, fmetas = routing.pack_bytes_flat(flat_in)
-    for o, r in zip(routing.unpack_bytes_flat(vec, fmetas), flat_in):
-        assert np.array_equal(np.asarray(o), np.asarray(r))
+    def same(out, ins):
+        assert len(out) == len(ins)
+        for o, r in zip(out, ins):
+            assert o.dtype == r.dtype and o.shape == r.shape
+            assert np.array_equal(np.asarray(o), np.asarray(r))
+
+    for lead in (1, 2):  # a2a_dense packs (p, w) rows with lead=1
+        buf, metas = routing.pack_words(rows, lead=lead)
+        assert buf.dtype == jnp.uint32 and buf.shape[:lead] == (4, 16)[:lead]
+        same(routing.unpack_words(buf, metas, lead=lead), rows)
+
+    # allgather: pack the local block flat, unpack the gathered (p, L) buffer
+    buf, metas = routing.pack_words([r[0] for r in rows], lead=0)
+    stacked = jnp.stack([buf, buf])
+    same(routing.unpack_words(stacked, metas, lead=1), [jnp.stack([r[0]] * 2) for r in rows])
+
+    # the ring's flat visitor block: mixed shapes and dtypes, one vector
+    flat_in = [rows[0], rows[3], rows[5], jnp.arange(9, dtype=jnp.int32)]
+    vec, fmetas = routing.pack_words(flat_in, lead=0)
+    assert vec.dtype == jnp.uint32 and vec.ndim == 1
+    same(routing.unpack_words(vec, fmetas, lead=0), flat_in)
+
+    # 64-bit composites (the int64 service keys) pack as two words each
+    with jax.enable_x64(True):
+        wide = jnp.asarray(
+            rng.integers(-(2**63), 2**63 - 1, (4, 16), dtype=np.int64)
+        )
+        flat_in = [wide, rows[2], jnp.arange(9, dtype=jnp.int32)]
+        vec, fmetas = routing.pack_words(flat_in, lead=0)
+        assert vec.dtype == jnp.uint32 and vec.size == 4 * 16 * 2 + 32 + 9
+        same(routing.unpack_words(vec, fmetas, lead=0), flat_in)
 
 
 @pytest.mark.fast

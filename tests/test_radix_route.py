@@ -2,9 +2,9 @@
 order-preserving unsigned mapping, the single-rung zero-retry guarantee,
 and the segmented composite path."""
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core import (
     SortConfig,
@@ -33,7 +33,7 @@ def test_radix_argsort_negative_and_boundary_int32():
 
 
 def test_radix_argsort_int64_extremes():
-    with enable_x64():
+    with jax.enable_x64(True):
         x = np.array(
             [I64.max, I64.min, 0, -1, 1, I64.min, I64.max, I64.min + 1],
             np.int64,
@@ -80,7 +80,7 @@ def test_radix_route_boundary_keys():
 
 
 def test_radix_route_int64_extremes():
-    with enable_x64():
+    with jax.enable_x64(True):
         rng = np.random.default_rng(1)
         x = rng.integers(I64.min, I64.max, (4, 32), dtype=np.int64)
         x[0, :2] = (I64.min, I64.max)
@@ -104,8 +104,6 @@ def test_radix_boundaries_monotone_and_complete():
     """The counted boundary vector is a valid partition of the local run:
     starts at 0, ends at n_p, nondecreasing — and equal keys never straddle
     a destination boundary (stability across the exchange)."""
-    import jax
-
     p, n_p = 4, 128
     x = np.sort(datagen.dense_int(p, n_p, seed=5, domain=16), axis=1)
 

@@ -1,0 +1,7 @@
+"""The chip benchmark: one command, driven by the files beside it.
+
+``python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>``
+runs one cell of ``BENCHMARK.json``. Everything that belongs to one
+configuration, traffic mix or per-layer metric is a file of its own under
+``bench/configs``, ``bench/traffic`` and ``bench/metrics``, found by name.
+"""

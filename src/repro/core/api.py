@@ -692,62 +692,23 @@ def _trace_meta_for(tracer, x, values, cat: str = "sort") -> Optional[Dict]:
     }
 
 
-def _trace_prepared(tracer, meta: Dict, cfg: SortConfig, prep: PreparedSort) -> None:
-    """Record the prepared distribution snapshot (host-side, traced runs only).
+def _prepare_span(tracer, meta: Optional[Dict], cfg: SortConfig):
+    """The host's launch of the prepare stage as a ``prepare`` span.
 
-    * ``route="radix"`` — the counted boundaries are exact: per-(src, dst)
-      send counts and byte volumes of the upcoming h-relation, before any
-      data moves.
-    * ``det`` — the tier-invariant splitters are in hand: searchsorting each
-      locally sorted run against them gives the splitter-implied boundary
-      *estimate* (tag-blind, so off by at most the duplicate runs) and hence
-      the oversampling skew the Lemma 5.1 bound is guarding against.
-    * ``iran``/``ran`` draw their sample inside the route stage (a retry
-      must be an independent trial), so there is nothing prepared to read.
+    It does not wait for the device: the stage's device time is in the
+    device trace, under its superstep scopes, so a traced run keeps the
+    untraced schedule. A null context when untraced.
     """
-    tid, cat = meta["tid"], meta.get("cat", "sort")
-    row_bytes = int(meta.get("row_bytes", 4))
-    if cfg.route == "radix" and prep.splits is not None:
-        sendc = host_send_counts(prep.splits[0])  # (p, p) exact counts
-        recv = sendc.sum(axis=0)
-        args = dict(
-            kind="radix_counts",
-            pair_max=int(sendc.max()),
-            recv_max=int(recv.max()),
-            imbalance=float(recv.max() / recv.mean()) if recv.mean() > 0 else 1.0,
-            row_bytes=row_bytes,
-        )
-        if cfg.p <= 64:
-            args["send_bytes"] = (sendc * row_bytes).tolist()  # per (src, dst)
-        tracer.point("distribution", cat=cat, tid=tid, **args)
-    elif cfg.algorithm == "det" and cfg.route == "sample" and prep.splits:
-        keys = np.asarray(prep.splits[0])[0]  # replicated (p-1,) splitter keys
-        xs = np.asarray(prep.xs)  # (p, n_per_proc), locally sorted
-        bounds = np.stack([np.searchsorted(row, keys) for row in xs])
-        sendc = np.diff(
-            np.concatenate(
-                [
-                    np.zeros((cfg.p, 1), np.int64),
-                    bounds,
-                    np.full((cfg.p, 1), xs.shape[1], np.int64),
-                ],
-                axis=1,
-            ),
-            axis=1,
-        )
-        recv = sendc.sum(axis=0)
-        args = dict(
-            kind="splitter_estimate",
-            pair_max=int(sendc.max()),
-            recv_max=int(recv.max()),
-            skew=float(recv.max() / recv.mean()) if recv.mean() > 0 else 1.0,
-            omega=cfg.omega_eff,
-            sample_size=cfg.s,
-            row_bytes=row_bytes,
-        )
-        if cfg.p <= 64:
-            args["send_bytes"] = (sendc * row_bytes).tolist()  # per (src, dst)
-        tracer.point("distribution", cat=cat, tid=tid, **args)
+    if tracer is None:
+        return contextlib.nullcontext()
+    return tracer.span(
+        "prepare",
+        tid=meta["tid"],
+        algorithm=cfg.algorithm,
+        route=cfg.route,
+        p=cfg.p,
+        n_per_proc=cfg.n_per_proc,
+    )
 
 
 def _radix_exact_ladder(cfg: SortConfig, prep: PreparedSort) -> tuple:
@@ -871,21 +832,7 @@ def bsp_sort_safe_launch(
                     return ex.prepare_vmap(cfg, nv)(x, *values)
             return ex.prepare_vmap(cfg, nv)(x, *values)
 
-        if tracer is not None:
-            # Traced runs block at the stage boundary so the prepare span is
-            # device-inclusive and the route spans start clean. Untraced runs
-            # keep full async dispatch.
-            with tracer.span(
-                "prepare",
-                tid=meta["tid"],
-                algorithm=cfg.algorithm,
-                route=cfg.route,
-                p=p,
-                n_per_proc=n_p,
-            ):
-                prep = jax.block_until_ready(_prepare())
-            _trace_prepared(tracer, meta, cfg, prep)
-        else:
+        with _prepare_span(tracer, meta, cfg):
             prep = _prepare()
         if cfg.route == "radix":
             # counts are in hand: collapse the ladder to one rung sized to
@@ -998,20 +945,7 @@ def bsp_sort_sharded_safe(
             cfg.tier_ladder(), rng, stats, run_tier, tracer=tracer, trace_meta=meta
         )
 
-    if tracer is not None:
-        with tracer.span(
-            "prepare",
-            tid=meta["tid"],
-            algorithm=cfg.algorithm,
-            route=cfg.route,
-            p=p,
-            n_per_proc=n_p,
-        ):
-            prep = jax.block_until_ready(
-                ex.prepare_sharded(cfg, mesh, mesh_axis, nv)(x, *values)
-            )
-        _trace_prepared(tracer, meta, cfg, prep)
-    else:
+    with _prepare_span(tracer, meta, cfg):
         prep = ex.prepare_sharded(cfg, mesh, mesh_axis, nv)(x, *values)
     ladder = cfg.tier_ladder()
     if cfg.route == "radix":

@@ -13,9 +13,11 @@ from typing import List, Sequence, Tuple
 import jax.numpy as jnp
 from jax import lax
 
+from .primitives import superstep
 from .radix import radix_argsort
 
 
+@superstep("ph2_local_sort")
 def local_sort(
     x: jnp.ndarray, method: str = "lax", values: Sequence[jnp.ndarray] = ()
 ) -> Tuple[jnp.ndarray, List[jnp.ndarray]]:

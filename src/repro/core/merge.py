@@ -36,9 +36,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .primitives import superstep
 from .types import sentinel_for
 
 
+@superstep("ph6_merge")
 def merge_by_sort(
     buf: jnp.ndarray, values: Sequence[jnp.ndarray] = ()
 ) -> Tuple[jnp.ndarray, List[jnp.ndarray]]:
@@ -131,6 +133,7 @@ def _rank_merge_two(
     return out, vout, jnp.minimum(ca + cb, w_out)
 
 
+@superstep("ph6_merge")
 def merge_tree(
     runs: jnp.ndarray,
     counts: jnp.ndarray,

@@ -8,13 +8,52 @@ already lower to bandwidth-optimal ICI ring/tree algorithms, so the BSP
 
 All functions run inside an ``axis_name`` region — under ``jax.vmap``
 (simulated processors) or ``jax.shard_map`` (real devices) interchangeably.
+
+:func:`superstep` names a BSP superstep on the device: every op a decorated
+stage emits carries the superstep's name in its ``op_name`` metadata.
 """
 from __future__ import annotations
 
-from typing import Sequence
+import functools
+from typing import Callable, Sequence
 
+import jax
 import jax.numpy as jnp
 from jax import lax
+
+#: the superstep scopes, in pipeline order (``radix_count`` is the radix
+#: route's counting pass, which takes the place of Ph3/Ph4)
+SUPERSTEPS = (
+    "ph2_local_sort",
+    "ph3_splitters",
+    "radix_count",
+    "ph4_partition",
+    "ph5_exchange",
+    "ph6_merge",
+)
+
+
+def superstep(name: str) -> Callable[[Callable], Callable]:
+    """Run the decorated stage inside ``jax.named_scope(name)``.
+
+    Metadata only: the ops, their fusion and the result are unchanged, so
+    the device trace can split time by superstep at no cost. Both runners
+    call the same stage functions, so ``vmap`` and ``shard_map`` programs
+    carry the same names (``jit(run)/vmap(ph5_exchange)/...``). A fresh
+    scope is entered per call, so concurrent tracing threads never share
+    one.
+    """
+    assert name in SUPERSTEPS, name
+
+    def wrap(fn: Callable) -> Callable:
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+
+        return scoped
+
+    return wrap
 
 
 def proc_id(axis: str) -> jnp.ndarray:

@@ -257,6 +257,7 @@ def _all_to_all_rows(rows: List[jnp.ndarray], cfg: SortConfig, axis: str):
     return [lax.all_to_all(r, axis, 0, 0) for r in rows]
 
 
+@prim.superstep("ph5_exchange")
 def recv_rows(
     x_sorted: jnp.ndarray,
     boundaries: jnp.ndarray,
@@ -315,6 +316,7 @@ def recv_rows(
     raise ValueError(f"recv_rows: unsupported routing {cfg.routing!r}")
 
 
+@prim.superstep("ph5_exchange")
 def compact_rows(
     rows: Sequence[jnp.ndarray],
     rcounts: jnp.ndarray,
@@ -411,6 +413,7 @@ def route_and_merge(
     return merged, mvals, count, overflow
 
 
+@prim.superstep("ph5_exchange")
 def _route_ring(x_sorted, boundaries, cfg, axis, values, sent):
     """p-1 ppermute supersteps; visitor block = one local run + boundaries.
 

@@ -50,10 +50,12 @@ from jax import lax
 
 from . import routing
 from .local_sort import local_sort
+from .primitives import superstep
 from .radix import _to_unsigned_order_preserving
 from .types import PreparedSort, SortConfig
 
 
+@superstep("radix_count")
 def radix_boundaries(
     xs: jnp.ndarray, p: int, axis: str
 ) -> jnp.ndarray:
@@ -79,9 +81,8 @@ def host_send_counts(bounds) -> np.ndarray:
     Host-side companion of :func:`radix_boundaries`: ``bounds`` is the
     prepared ``splits[0]`` — (p, p+1) under the global layout, one row per
     source — and differencing each row yields the exact h-relation count
-    matrix. Shared by the launch driver's single-rung capacity sizing and
-    the tracer's per-(src, dst) byte-volume record; reading it is the radix
-    launch path's only host sync.
+    matrix, from which the launch driver sizes the single capacity rung;
+    reading it is the radix launch path's only host sync.
     """
     return np.diff(np.asarray(bounds), axis=1)
 

@@ -55,23 +55,25 @@ def route_ran_spmd(
 
     # Fig. 2 steps 2-5: random sample, gathered and sorted "at processor 0"
     # (deterministically replicated here — same result, one superstep).
-    k = jax.random.fold_in(rng, me)
-    pos = jax.random.randint(k, (cfg.s,), 0, n_p)
-    local_sample = x[pos]
-    gathered = lax.all_gather(local_sample, axis).reshape(-1)
-    ybar = jnp.sort(gathered)
-    # Step 6: p-1 evenly spaced splitters.
-    splits = ybar[jnp.arange(1, p) * cfg.s - 1]
+    with jax.named_scope("ph3_splitters"):
+        k = jax.random.fold_in(rng, me)
+        pos = jax.random.randint(k, (cfg.s,), 0, n_p)
+        local_sample = x[pos]
+        gathered = lax.all_gather(local_sample, axis).reshape(-1)
+        ybar = jnp.sort(gathered)
+        # Step 6: p-1 evenly spaced splitters.
+        splits = ybar[jnp.arange(1, p) * cfg.s - 1]
 
     # Step 9: destination of every (unsorted) key + set formation (stable
     # integer sort by destination — the D·n/p operation).
-    dest = jnp.searchsorted(splits, x, side="right").astype(jnp.int32)
-    order = jnp.argsort(dest, stable=True)
-    xg = x[order]
-    vals = [v[order] for v in values]
-    bounds = jnp.searchsorted(
-        dest[order], jnp.arange(p + 1, dtype=jnp.int32), side="left"
-    ).astype(jnp.int32)
+    with jax.named_scope("ph4_partition"):
+        dest = jnp.searchsorted(splits, x, side="right").astype(jnp.int32)
+        order = jnp.argsort(dest, stable=True)
+        xg = x[order]
+        vals = [v[order] for v in values]
+        bounds = jnp.searchsorted(
+            dest[order], jnp.arange(p + 1, dtype=jnp.int32), side="left"
+        ).astype(jnp.int32)
 
     # Steps 10-11: routing; Step 12: full local sort (not a merge).
     buf, vbufs, count, overflow = routing.route(xg, bounds, cfg, axis, vals)

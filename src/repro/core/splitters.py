@@ -120,6 +120,7 @@ def select_splitters(cfg: SortConfig, sample: Tagged, axis: str, mode: str) -> T
 
 
 # ---------------------------------------------------- tagged binary search
+@prim.superstep("ph4_partition")
 def searchsorted_tagged(
     x_sorted: jnp.ndarray,
     splitters: Tagged,
@@ -167,6 +168,7 @@ def splitters_from_sorted_sample(
     return select_splitters(cfg, sorted_sample, axis, "bitonic")
 
 
+@prim.superstep("ph3_splitters")
 def splitter_stage(
     x_sorted: jnp.ndarray, cfg: SortConfig, axis: str, rng: jax.Array | None = None
 ) -> Tagged:

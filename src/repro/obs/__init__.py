@@ -8,10 +8,11 @@ compiled program):
   scattered telemetry of ``TierStats``, the service dispatcher, the
   capacity planner and the serve engine now lives here, with the old
   attributes kept as thin property views.
-* :class:`Tracer` (``trace.py``) — superstep spans recorded at the sort
-  drivers' launch/wait boundaries and the dispatcher's
-  queue→form→launch→flight pipeline, exported as Chrome ``trace_event``
-  JSON. Off by default; enable per run via ``SortConfig(obs=tracer)`` /
+* :class:`Tracer` (``trace.py``) — spans recorded at the sort drivers'
+  launch/wait boundaries, the service's pending→queue→form→launch→flight
+  pipeline and its lock waits, exported as Chrome ``trace_event`` JSON
+  and mappable onto the profiler's device trace (README.md). Off by
+  default; enable per run via ``SortConfig(obs=tracer)`` /
   ``ServiceConfig(obs=tracer)``.
 * the fitted machine profile (``profile.py``) — least-squares (g, L) over
   the traced h sizes and measured superstep walls, plus the per-run cost

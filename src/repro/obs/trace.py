@@ -1,19 +1,21 @@
 """Superstep spans — host-side tracing of the BSP sort/dispatch pipeline.
 
 A :class:`Tracer` records *spans* (named intervals with labeled args) and
-*points* (instant events: host syncs, distribution snapshots) from the
-launch/wait boundaries of the sort drivers and the service dispatcher.
-Everything the tracer touches is host-side Python: span bodies wrap jitted
-*calls*, never traced code, so an untraced run's compiled programs are
-byte-for-byte identical (``SortConfig.obs`` is excluded from the config's
-equality/hash — see ``core/types.py``) and a traced run differs only in
-host-side bookkeeping plus the explicit block-at-boundary syncs that make
-span durations meaningful.
+*points* (instant events: host syncs, batch shapes) from the launch/wait
+boundaries of the sort drivers, the service dispatcher and the service's
+front end. Everything the tracer touches is host-side Python: span bodies
+wrap jitted *calls*, never traced code, so an untraced run's compiled
+programs are byte-for-byte identical (``SortConfig.obs`` is excluded from
+the config's equality/hash — see ``core/types.py``), and a traced run keeps
+the untraced schedule: it adds host-side bookkeeping and no device sync.
+Device time per BSP superstep comes from the profiler's device trace, whose
+ops carry the superstep scopes (``core/primitives.py``); README.md shows how
+to put these spans on that trace's clock.
 
 Span schema (one dict per span; see ``src/repro/obs/README.md``)::
 
-    name  str   "prepare" | "route" | "queue" | "form" | "launch" |
-                "flight" | ...
+    name  str   "prepare" | "route" | "pending" | "lock_wait" | "queue" |
+                "form" | "launch" | "flight" | ...
     cat   str   "sort" | "dispatch" | "moe" | ...
     tid   str   timeline lane ("sort0", "batch3", ...)
     t0    float perf_counter seconds at span start
@@ -128,7 +130,7 @@ class Tracer:
             )
 
     def point(self, name: str, cat: str = "sort", tid: str = "main", **args):
-        """Record one instant event (host syncs, distribution snapshots)."""
+        """Record one instant event (host syncs, batch shapes)."""
         self.points.append(
             {
                 "name": name,

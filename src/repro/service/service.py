@@ -46,6 +46,7 @@ instance (and every other sort caller) shares compiled programs per bucket.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 import time
@@ -152,6 +153,7 @@ class _Pending:
     rid: int
     keys: np.ndarray
     future: SortFuture
+    t_submit: float = 0.0  # tracer clock at submit (traced runs only)
 
 
 class SortService:
@@ -201,6 +203,10 @@ class SortService:
         # to call from a background driver thread (start_driver) alongside
         # the submitting thread. Reentrant: _drive flushes under the lock.
         self._lock = threading.RLock()
+        # traced runs time each caller's wait for the lock (lock_wait
+        # spans) and each request's stay here (pending spans)
+        self._tracer = obs.resolve_tracer(cfg.obs)
+        self._held = threading.local()  # lock depth of the calling thread
         self._driver: Optional[threading.Thread] = None
         self._driver_stop = threading.Event()
         # telemetry — lives in the process-wide metrics registry under the
@@ -276,6 +282,37 @@ class SortService:
     def start_tiers(self) -> Dict[str, int]:
         return self.dispatcher.start_tiers
 
+    # -------------------------------------------------------------- lock
+    def _locked(self, entry: str):
+        """The service lock, as a context manager for ``entry``.
+
+        Untraced, the plain lock. Traced, the outermost acquisition on a
+        thread records a ``lock_wait`` span on that thread's lane: how long
+        ``entry`` waited, e.g. while the driver held the lock through a
+        flight.
+        """
+        if self._tracer is None or getattr(self._held, "depth", 0):
+            return self._lock
+        return self._timed_lock(entry)
+
+    @contextlib.contextmanager
+    def _timed_lock(self, entry: str):
+        tr = self._tracer
+        t0 = tr.now()
+        with self._lock:
+            tr.add_span(
+                "lock_wait",
+                t0,
+                cat="service",
+                tid=threading.current_thread().name,
+                entry=entry,
+            )
+            self._held.depth = 1
+            try:
+                yield
+            finally:
+                self._held.depth = 0
+
     # ------------------------------------------------------------- queue
     def submit(
         self,
@@ -312,8 +349,9 @@ class SortService:
         depends on the view the previous one produced — so the future
         returns already resolved.
         """
+        t_submit = self._tracer.now() if self._tracer is not None else 0.0
         arr = np.asarray(keys, np.int32).reshape(-1)
-        with self._lock:
+        with self._locked("submit"):
             rid = self._next_rid
             self._next_rid += 1
             if stream is not None:
@@ -336,7 +374,7 @@ class SortService:
             if deadline_s is not None:
                 fut.deadline_at = fut.submitted_at + float(deadline_s)
             fut._canceller = self._cancel
-            self._pending.append(_Pending(rid, arr, fut))
+            self._pending.append(_Pending(rid, arr, fut, t_submit))
             if (
                 self.cfg.max_pending is not None
                 and len(self._pending) >= self.cfg.max_pending
@@ -377,18 +415,44 @@ class SortService:
         overlapping any in-flight device work). Returns whether anything
         was enqueued.
         """
-        with self._lock:
+        with self._locked("flush_async"):
             self._expire_deadlines()
             todo, self._pending = self._pending, []
             if todo:
                 self._count_flush(trigger)
-            fut_by_rid = {r.rid: r.future for r in todo}
-            for batch in self.former.form([(r.rid, r.keys) for r in todo]):
-                self.dispatcher.enqueue(
-                    batch, {rid: fut_by_rid[rid] for rid in batch.rids}
-                )
-            self.dispatcher.pump()
+            self._enqueue(
+                self.former.form([(r.rid, r.keys) for r in todo]),
+                {r.rid: r for r in todo},
+                trigger,
+            )
             return bool(todo)
+
+    def _enqueue(self, batches, by_rid: Dict[int, _Pending], trigger: str) -> None:
+        """Hand formed batches to the dispatcher and launch what fits.
+
+        Traced runs close one ``pending`` span per request here: from its
+        ``submit`` to its batch entering the dispatcher queue, labelled
+        with the flush ``trigger``.
+        """
+        tr = self._tracer
+        for batch in batches:
+            self.dispatcher.enqueue(
+                batch, {rid: by_rid[rid].future for rid in batch.rids}
+            )
+            if tr is not None:
+                t_end = tr.now()
+                for rid in batch.rids:
+                    tr.add_span(
+                        "pending",
+                        by_rid[rid].t_submit,
+                        t_end=t_end,
+                        cat="service",
+                        tid="pending",
+                        rid=rid,
+                        n_keys=int(by_rid[rid].keys.size),
+                        trigger=trigger,
+                    )
+        self.dispatcher.pump()
 
     def flush_ready(self, min_keys: Optional[int] = None) -> bool:
         """Admission-aware launch for open-loop arrival pumps.
@@ -399,23 +463,17 @@ class SortService:
         ``flush`` clears it, so nothing starves. Non-blocking; returns
         whether any batch launched.
         """
-        with self._lock:
+        with self._locked("flush_ready"):
             self._expire_deadlines()
             todo, self._pending = self._pending, []
-            fut_by_rid = {r.rid: r.future for r in todo}
+            by_rid = {r.rid: r for r in todo}
             batches, held = self.former.form_ready(
                 [(r.rid, r.keys) for r in todo], min_keys=min_keys
             )
             if batches:
                 self._count_flush("ready")
-            for batch in batches:
-                self.dispatcher.enqueue(
-                    batch, {rid: fut_by_rid[rid] for rid in batch.rids}
-                )
-            self._pending = [
-                _Pending(rid, keys, fut_by_rid[rid]) for rid, keys in held
-            ] + self._pending
-            self.dispatcher.pump()
+            self._pending = [by_rid[rid] for rid, _ in held] + self._pending
+            self._enqueue(batches, by_rid, "ready")
             return bool(batches)
 
     def flush(self, trigger: str = "manual") -> Dict[int, RequestResult]:
@@ -429,7 +487,7 @@ class SortService:
         result from the store. A failed request does NOT raise here — its
         future (and ``take_result``) carries the :class:`SortServiceError`.
         """
-        with self._lock:
+        with self._locked("flush"):
             self.flush_async(trigger)
             try:
                 self.dispatcher.drain()
@@ -446,7 +504,7 @@ class SortService:
 
     def _drive(self, fut: SortFuture) -> None:
         """SortFuture's engine: launch anything queued, run until it lands."""
-        with self._lock:
+        with self._locked("_drive"):
             if any(r.rid == fut.rid for r in self._pending):
                 self.flush_async(trigger="claim")
             self.dispatcher.drive(fut)
@@ -461,7 +519,7 @@ class SortService:
         False and runs to completion. On success the future resolves with
         a :class:`SortCancelledError` — the request never launches.
         """
-        with self._lock:
+        with self._locked("_cancel"):
             if fut.done():
                 return False
             was_pending = any(r.rid == fut.rid for r in self._pending)
@@ -485,7 +543,7 @@ class SortService:
         formed into the dispatcher's batch queue (its own sweep unpicks
         them). Launched requests are never expired.
         """
-        with self._lock:
+        with self._locked("_expire_deadlines"):
             now = time.perf_counter() if now is None else now
             expired = [
                 r
@@ -521,7 +579,7 @@ class SortService:
         Callable from a thread (:meth:`start_driver`) or polled from an
         event loop. Returns whether work remains.
         """
-        with self._lock:
+        with self._locked("run_pending"):
             self._expire_deadlines()
             self.maybe_flush()
             busy = self.dispatcher.run_pending(max_steps=max_steps)
@@ -535,7 +593,7 @@ class SortService:
         is idle; futures resolve in the background and ``result()`` returns
         without driving.
         """
-        with self._lock:
+        with self._locked("start_driver"):
             if self._driver is not None and self._driver.is_alive():
                 return
             self._driver_stop.clear()

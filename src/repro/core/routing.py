@@ -39,6 +39,22 @@ All schedules preserve source order: the receive buffer is compacted by
 (source proc, local index), which is what makes the final merge stable and
 the §5.1.1 duplicate handling free.
 
+Windowed copies
+---------------
+Both ends of ``a2a_dense`` move data that is contiguous by construction, so
+they copy whole runs, never key by key. The send side
+(:func:`_segment_rows`) takes destination i's row as one
+``lax.dynamic_slice`` window ``x_sorted[b[i] : b[i] + pair_cap]`` of the
+tail-padded local run; the receive side (:func:`compact_rows`) writes each
+received row whole at its source's offset, in source order, so each row's
+pad tail is overwritten by the next row. Under the vmap runner these lower
+to batched gathers of slice size ``pair_cap`` and scatters with unique,
+sorted indices (p windows each), which the TPU's compiler turns into loops
+of window copies; under ``shard_map`` they stay plain dynamic slices and
+updates. Per-key indices cost far more: on a TPU v5e a gather of one key
+per index and an unsorted scatter of n_max indices (which it sorts first)
+took 90 % of a 2^26-key bulk call.
+
 Capacity-tier ladder & retry semantics
 --------------------------------------
 A sort may never drop keys, but every fixed-shape schedule above has a
@@ -68,7 +84,7 @@ is the key-value form used by MoE token dispatch (models/moe.py) and the
 segmented SortService composites. With ``merge="tree"`` they also ride the
 rank-merge tail (:func:`route_and_merge`): rank positions are computed once
 on the keys and applied to every payload, so key-value callers skip the
-``compact_rows`` scatter + full re-sort entirely.
+``compact_rows`` compaction + full re-sort entirely.
 """
 from __future__ import annotations
 
@@ -234,17 +250,28 @@ def _segment_rows(
 ) -> List[jnp.ndarray]:
     """Slice the local run into p destination rows of static width.
 
-    rows[i, t] = arr[b[i] + t] for t < c_i else pad — one gather per array.
+    Row i is the window ``arr[b[i] : b[i] + width]``, copied whole, with
+    slots ``t >= c_i`` set to the pad. Each array is first padded at its
+    tail by ``width`` slots, because ``lax.dynamic_slice`` clamps a start
+    that would run past the end (which would shift the run); with the pad,
+    ``b[i] + width`` never passes the padded length. Under the vmap runner
+    each window is a gather of slice size ``width``, not one index per key.
     """
-    n_p = arrs[0].shape[0]
-    t = jnp.arange(width)[None, :]
-    idx = jnp.clip(boundaries[:-1][:, None] + t, 0, n_p - 1)
-    valid = t < counts[:, None]
+    starts = boundaries[:-1]
+    valid = jnp.arange(width)[None, :] < counts[:, None]
     rows = []
     for i, a in enumerate(arrs):
-        g = a[idx]  # (p, width, ...)
         fill = key_sentinel if i == 0 else _pad_value_for(a)
-        mask = valid.reshape(valid.shape + (1,) * (g.ndim - 2))
+        tail = a.shape[1:]
+        padded = jnp.concatenate([a, jnp.full((width,) + tail, fill, a.dtype)])
+        zeros = (0,) * len(tail)
+        g = jnp.stack(
+            [
+                lax.dynamic_slice(padded, (starts[d],) + zeros, (width,) + tail)
+                for d in range(starts.shape[0])
+            ]
+        )  # (p, width, ...)
+        mask = valid.reshape(valid.shape + (1,) * len(tail))
         rows.append(jnp.where(mask, g, fill))
     return rows
 
@@ -323,21 +350,27 @@ def compact_rows(
     cap: int,
     key_sentinel: jnp.ndarray,
 ) -> List[jnp.ndarray]:
-    """Scatter (p, w, ...) rows into a (cap, ...) buffer ordered by source.
+    """Lay (p, w, ...) rows end to end in a (cap, ...) buffer, by source.
 
-    Row j's first r_j entries land at offsets[j]..; the rest are dropped
-    (index == cap with mode='drop'). Pads end at the tail.
+    Row j's first r_j entries land at ``offsets[j]`` onward. Each row is
+    written whole, as one window, into a (cap + w, ...) buffer of pad, in
+    source order: every row is already pad past r_j, so row j+1 overwrites
+    row j's pad tail, and the last row's tail is pad. The writes stay p
+    ordered updates (one scatter with overlapping windows leaves their
+    order undefined). A start past ``cap`` (an overflowing tier, whose
+    buffers the overflow-safe sort discards) is clamped to ``cap`` and
+    lands in the slack that ``[:cap]`` cuts off. Pads end at the tail.
     """
     offsets = prim.exclusive_cumsum(rcounts)
     p, w = rows[0].shape[:2]
-    t = jnp.arange(w)[None, :]
-    valid = t < rcounts[:, None]
-    idx = jnp.where(valid, offsets[:, None] + t, cap).reshape(-1)
     out = []
     for i, r in enumerate(rows):
         fill = key_sentinel if i == 0 else _pad_value_for(r)
-        buf = jnp.full((cap,) + r.shape[2:], fill, r.dtype)
-        out.append(buf.at[idx].set(r.reshape((p * w,) + r.shape[2:]), mode="drop"))
+        zeros = (0,) * (r.ndim - 2)
+        buf = jnp.full((cap + w,) + r.shape[2:], fill, r.dtype)
+        for j in range(p):
+            buf = lax.dynamic_update_slice(buf, r[j], (offsets[j],) + zeros)
+        out.append(buf[:cap])
     return out
 
 
@@ -393,7 +426,7 @@ def route_and_merge(
     ``merge=tree`` rank-merge path valid (``ran`` routes dest-grouped, not
     key-sorted, rows and must keep its own sort-based tail). The tree tail
     is payload-generic: received rows (key + payloads) go straight into
-    :func:`merge.merge_tree`, skipping the ``compact_rows`` scatter and the
+    :func:`merge.merge_tree`, skipping the ``compact_rows`` compaction and the
     full O(n_max·lg²n_max) re-sort of the sort tail.
     """
     if cfg.merge == "tree" and cfg.routing != "ring":

@@ -605,6 +605,8 @@ class InFlightSort:
         args = dict(
             tier=tier,
             rung=self._i,
+            runner=self._meta["runner"],
+            routing=tier_cfg.routing,
             ok=ok,
             sync_s=round(t_end - t_sync, 6),
             h_words=h_words,
@@ -681,13 +683,15 @@ def _escalate(
     ).wait()
 
 
-def _trace_meta_for(tracer, x, values, cat: str = "sort") -> Optional[Dict]:
-    """Per-launch trace metadata: a fresh timeline lane + packed row width."""
+def _trace_meta_for(tracer, x, values, runner: str, cat: str = "sort") -> Optional[Dict]:
+    """Per-launch trace metadata: a fresh timeline lane, the runner
+    (``"vmap"`` or ``"sharded"``) and the packed row width."""
     if tracer is None:
         return None
     return {
         "tid": tracer.next_tid("sort"),
         "cat": cat,
+        "runner": runner,
         "row_bytes": routing.packed_row_bytes(x.dtype, [v.dtype for v in values]),
     }
 
@@ -704,8 +708,10 @@ def _prepare_span(tracer, meta: Optional[Dict], cfg: SortConfig):
     return tracer.span(
         "prepare",
         tid=meta["tid"],
+        runner=meta["runner"],
         algorithm=cfg.algorithm,
         route=cfg.route,
+        routing=cfg.routing,
         p=cfg.p,
         n_per_proc=cfg.n_per_proc,
     )
@@ -788,7 +794,7 @@ def bsp_sort_safe_launch(
         # pin a Tracer or FaultPlan. (Both are hash/compare-excluded —
         # this changes no cache key.)
         cfg = dataclasses.replace(cfg, obs=None, chaos=None)
-    meta = _trace_meta_for(tracer, x, values)
+    meta = _trace_meta_for(tracer, x, values, "vmap")
     if rng is None:
         rng = jax.random.key(cfg.seed)
     ex = executor if executor is not None else _EXECUTOR
@@ -924,7 +930,7 @@ def bsp_sort_sharded_safe(
         # chaos injection targets the vmapped service path; the sharded
         # driver only strips the handle so executor keys stay clean
         cfg = dataclasses.replace(cfg, obs=None, chaos=None)
-    meta = _trace_meta_for(tracer, x, values)
+    meta = _trace_meta_for(tracer, x, values, "sharded")
     if rng is None:
         rng = jax.random.key(cfg.seed)
     ex = executor if executor is not None else _EXECUTOR

@@ -21,8 +21,8 @@ Span schema (one dict per span; see ``src/repro/obs/README.md``)::
     t0    float perf_counter seconds at span start
     dur   float span length in seconds (>= 0)
     args  dict  JSON-able labels/measurements, notably for "route" spans:
-                tier, rung, ok, h_words, supersteps, recv_max, recv_mean,
-                imbalance, sync_s
+                tier, rung, runner, routing, ok, h_words, supersteps,
+                recv_max, recv_mean, imbalance, sync_s
 
 ``chrome_trace()`` exports the standard Chrome ``trace_event`` JSON
 (load in chrome://tracing or Perfetto): spans become ``ph="X"`` complete

@@ -1,28 +1,12 @@
-"""Distributed (shard_map) paths on 8 host devices — run in a subprocess so
-the main pytest process keeps seeing exactly 1 CPU device (per the brief)."""
-import os
-import subprocess
-import sys
-import textwrap
+"""Distributed (shard_map) paths on 8 host devices (4 for the sharded
+sort's check, one per chip of a v5e 2x2) — run in a subprocess so the main
+pytest process keeps seeing exactly 1 CPU device (per the brief)."""
+import json
 
+import numpy as np
 import pytest
 
-_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _run(src: str):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.path.join(_ROOT, "src")
-    r = subprocess.run(
-        [sys.executable, "-c", textwrap.dedent(src)],
-        capture_output=True,
-        text=True,
-        env=env,
-        timeout=560,
-    )
-    assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-3000:]}"
-    return r.stdout
+from host_devices import run_on_host_devices as _run
 
 
 def test_sharded_sort_equals_simulated():
@@ -43,30 +27,70 @@ def test_sharded_sort_equals_simulated():
     assert "OK" in out
 
 
-def test_sharded_safe_driver_resumes_and_caches_shard_map():
-    out = _run("""
+#: inputs of the overflow-safe sharded sort's check: the paper's [U], [G] and [DD], zipf
+#: duplicates, and one input whose every run aims at one bucket (it
+#: overflows ``whp`` and must escalate up the ladder)
+SHARDED_SAFE_INPUTS = ["U", "G", "DD", "zipf", "adversarial"]
+
+
+@pytest.fixture(scope="module")
+def sharded_safe_runs(tmp_path_factory):
+    """``bsp_sort_sharded_safe`` (iran, a2a_dense, whp first) with an int32
+    position payload on four host devices, one run per input; each input
+    and what came back is saved to ``<name>.npz``."""
+    out = tmp_path_factory.mktemp("sharded_safe")
+    _run("""
+        import dataclasses, json, sys
         import numpy as np, jax, jax.numpy as jnp
-        from jax.sharding import Mesh
-        from repro.core import SortConfig, SortExecutor, bsp_sort_sharded_safe, gathered_output
-        p, n_p = 8, 2048
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec
+        from repro.core import SortConfig, SortExecutor, bsp_sort_sharded_safe, datagen, gathered_output
+        names, out = json.loads(sys.argv[1]), sys.argv[2]
+        p, n_p = len(jax.devices()), 2048
         mesh = Mesh(np.array(jax.devices()), ("procs",))
-        xadv = jnp.asarray(np.repeat((np.arange(p, dtype=np.int32) * 1000)[:, None], n_p, axis=1))
-        cfg = SortConfig(p=p, n_per_proc=n_p, algorithm="iran", pair_capacity="whp")
-        ex = SortExecutor()
-        res, _, st = bsp_sort_sharded_safe(xadv, mesh, "procs", cfg, executor=ex)
-        assert st.retries >= 1, st.as_row()  # escalated past whp
-        assert np.array_equal(gathered_output(res), np.sort(np.asarray(xadv).ravel()))
-        # regression: repeated calls with the same mesh/cfg must NOT rebuild
-        # shard_map — the executor's counting wrapper sees zero new traces
-        first = dict(ex.trace_counts)
-        assert all(v == 1 for v in first.values()), first
-        res2, _, st2 = bsp_sort_sharded_safe(xadv, mesh, "procs", cfg, executor=ex)
-        assert dict(ex.trace_counts) == first, (ex.trace_counts, first)
-        # one shared prepare callable across all rungs of the ladder
-        assert sum(1 for k in first if k[0] == "prepare") == 1
+        shard = NamedSharding(mesh, PartitionSpec("procs"))
+        cfg = SortConfig(p=p, n_per_proc=n_p, algorithm="iran", routing="a2a_dense", pair_capacity="whp")
+        pos = jax.device_put(jnp.arange(p * n_p, dtype=jnp.int32).reshape(p, n_p), shard)
+        for name in names:
+            if name == "adversarial":
+                keys = np.repeat((np.arange(p, dtype=np.int32) * 1000)[:, None], n_p, axis=1)
+            else:
+                keys = datagen.generate(name, p, n_p, seed=11)
+            x = jax.device_put(jnp.asarray(keys), shard)
+            ex = SortExecutor()
+            res, vbufs, st = bsp_sort_sharded_safe(x, mesh, "procs", cfg, values=[pos], executor=ex)
+            first = dict(ex.trace_counts)
+            # a repeated call with the same mesh and cfg rebuilds no shard_map
+            bsp_sort_sharded_safe(x, mesh, "procs", cfg, values=[pos], executor=ex)
+            np.savez(
+                f"{out}/{name}.npz",
+                keys=keys,
+                got_keys=gathered_output(res),
+                got_payload=gathered_output(dataclasses.replace(res, buf=vbufs[0])),
+                retries=st.retries,
+                last_tier=st.last_tier,
+                traced_once=all(v == 1 for v in first.values()),
+                retraced=dict(ex.trace_counts) != first,
+                prepare_programs=sum(1 for k in first if k[0] == "prepare"),
+            )
         print("OK")
-    """)
-    assert "OK" in out
+    """, json.dumps(SHARDED_SAFE_INPUTS), str(out), devices=4)
+    return out
+
+
+@pytest.mark.parametrize("name", SHARDED_SAFE_INPUTS)
+def test_sharded_safe_sort_matches_the_stable_reference(name, sharded_safe_runs):
+    """Keys equal ``np.sort`` and the payload equals the stable argsort,
+    exactly; the sort runs one prepare program for every rung it tries
+    and re-enters its cached shard_map programs on a repeated call."""
+    run = np.load(sharded_safe_runs / f"{name}.npz")
+    keys = run["keys"].ravel()
+    assert np.array_equal(run["got_keys"], np.sort(keys))
+    assert np.array_equal(run["got_payload"], np.argsort(keys, kind="stable"))
+    assert run["traced_once"] and not run["retraced"]
+    assert run["prepare_programs"] == 1
+    if name == "adversarial":
+        # escalated past whp and whp2 to the exact rung
+        assert run["retries"] >= 1 and str(run["last_tier"]) == "exact"
 
 
 def test_executor_mesh_keyed_cache_no_cross_mesh_reuse():

@@ -1,6 +1,8 @@
 """The program's own instrumentation: superstep scopes in the compiled
-programs, the service's ``pending``/``lock_wait`` spans, and a traced launch
-that never blocks on the device."""
+programs of both runners, the sorts' ``prepare``/``route`` spans, the
+service's ``pending``/``lock_wait`` spans, and a traced launch that never
+blocks on the device."""
+import json
 import re
 import threading
 import time
@@ -17,10 +19,56 @@ from repro.core.api import SortExecutor, _radix_exact_ladder, bsp_sort_safe_laun
 from repro.core.primitives import SUPERSTEPS
 from repro.service import ServiceConfig, SortService
 
+from host_devices import run_on_host_devices
+
 pytestmark = pytest.mark.fast
 
 P, N_P = 8, 512
 _WRAPPER = re.compile(r"^\w*\((.*)\)$")
+#: the sharded cases run on four host devices, one processor each
+SHARDED_P = 4
+#: (name, SortConfig keywords) of the programs the sharded cases compile
+SHARDED_CASES = {"sharded-iran": dict(algorithm="iran"), "sharded-det": dict(algorithm="det")}
+
+_SHARDED_SRC = """
+import dataclasses, json, sys
+import numpy as np, jax, jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from repro import obs
+from repro.core import SortConfig, datagen
+from repro.core.api import SortExecutor, bsp_sort_sharded_safe
+cases, n_p = json.loads(sys.argv[1]), int(sys.argv[2])
+p = len(jax.devices())
+mesh = Mesh(np.array(jax.devices()), ("procs",))
+shard = NamedSharding(mesh, PartitionSpec("procs"))
+x = jax.device_put(jnp.asarray(datagen.generate("U", p, n_p, seed=3)), shard)
+v = jax.device_put(jnp.arange(p * n_p, dtype=jnp.int32).reshape(p, n_p), shard)
+rng = jax.random.key_data(jax.random.key(0))
+out = {}
+for name, kw in cases.items():
+    cfg = SortConfig(p=p, n_per_proc=n_p, pair_capacity="whp", **kw)
+    ex = SortExecutor()
+    prepare = ex.prepare_sharded(cfg, mesh, "procs", 1)
+    route = ex.route_sharded(cfg.tier_ladder()[0][1], mesh, "procs", 1)
+    prep = prepare(x, v)
+    t = obs.Tracer()
+    bsp_sort_sharded_safe(x, mesh, "procs", dataclasses.replace(cfg, obs=t), values=[v], executor=ex)
+    out[name] = {
+        "prepare": prepare.lower(x, v).compile().as_text(),
+        "route": route.lower(prep, rng).compile().as_text(),
+        "spans": [(s["name"], s["args"]) for s in t.spans],
+    }
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def sharded_programs():
+    """Compiled prepare/route text and sort spans of each sharded case,
+    from a subprocess on ``SHARDED_P`` host devices (this process keeps
+    seeing one device)."""
+    out = run_on_host_devices(_SHARDED_SRC, json.dumps(SHARDED_CASES), str(N_P), devices=SHARDED_P, timeout=300)
+    return json.loads(out.splitlines()[-1])
 
 
 def scopes_in(hlo_text: str) -> set:
@@ -53,10 +101,27 @@ def scopes_in(hlo_text: str) -> set:
          {"ph3_splitters", "ph4_partition", "ph5_exchange", "ph6_merge"}),
         (dict(algorithm="iran", routing="allgather"), {"ph2_local_sort"},
          {"ph3_splitters", "ph4_partition", "ph5_exchange", "ph6_merge"}),
+        # the sharded runner: one processor per device under shard_map, Ph5 a
+        # real all-to-all between the devices
+        (SHARDED_CASES["sharded-iran"], {"ph2_local_sort"},
+         {"ph3_splitters", "ph4_partition", "ph5_exchange", "ph6_merge"}),
+        (SHARDED_CASES["sharded-det"], {"ph2_local_sort", "ph3_splitters"},
+         {"ph4_partition", "ph5_exchange", "ph6_merge"}),
     ],
-    ids=["det", "iran", "ran", "radix", "iran-tree", "iran-ring", "iran-allgather"],
+    ids=["det", "iran", "ran", "radix", "iran-tree", "iran-ring", "iran-allgather",
+         "sharded-iran", "sharded-det"],
 )
-def test_compiled_programs_carry_their_superstep_scopes(kw, prepare_scopes, route_scopes):
+def test_compiled_programs_carry_their_superstep_scopes(kw, prepare_scopes, route_scopes, request):
+    case = request.node.callspec.id
+    if case in SHARDED_CASES:
+        texts = request.getfixturevalue("sharded_programs")[case]
+        assert scopes_in(texts["prepare"]) == prepare_scopes
+        assert scopes_in(texts["route"]) == route_scopes
+        # every all-to-all of the route, the Ph5 exchange's included, sits
+        # under ph5_exchange
+        a2a = [line for line in texts["route"].splitlines() if " all-to-all(" in line]
+        assert a2a and all(scopes_in(line) == {"ph5_exchange"} for line in a2a)
+        return
     x = jnp.asarray(datagen.generate("U", P, N_P, seed=3))
     v = jnp.arange(P * N_P, dtype=jnp.int32).reshape(P, N_P)
     cfg = SortConfig(p=P, n_per_proc=N_P, **kw)
@@ -89,6 +154,22 @@ def test_traced_launch_never_blocks_on_the_device(kw, monkeypatch):
     assert np.array_equal(gathered_output(res), np.sort(np.asarray(x).ravel()))
     assert [s["name"] for s in t.spans] == ["prepare", "route"]
     assert not [p for p in t.points if p["name"] == "distribution"]
+
+
+@pytest.mark.parametrize("runner", ["vmap", "sharded"])
+def test_sort_spans_name_their_runner_and_routing(runner, request):
+    if runner == "sharded":
+        spans = request.getfixturevalue("sharded_programs")["sharded-iran"]["spans"]
+    else:
+        x = jnp.asarray(datagen.generate("U", P, N_P, seed=5))
+        t = obs.Tracer()
+        bsp_sort_safe_launch(
+            x, SortConfig(p=P, n_per_proc=N_P, algorithm="iran", pair_capacity="whp", obs=t)
+        ).wait()
+        spans = [(s["name"], s["args"]) for s in t.spans]
+    assert [name for name, _ in spans] == ["prepare", "route"]
+    assert all(args["runner"] == runner for _, args in spans)
+    assert all(args["routing"] == "a2a_dense" for _, args in spans)
 
 
 def _arrays(sizes, seed=0):

@@ -439,7 +439,7 @@ def table_service_soak(
         if lag > 0:
             time.sleep(lag)
         futs.append(svc.submit(a))
-        svc.flush_ready()  # full batches launch mid-stream, tail held
+        svc.flush_ready()  # full batches launch mid-stream; tail held behind a flight
     futs += [svc.submit(a) for a in burst]  # no trigger: queued unlaunched
     svc.flush()  # drain: >= 2 batches in flight before the first wait
     wall = time.time() - t0

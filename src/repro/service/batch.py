@@ -94,9 +94,12 @@ class BatchFormer:
         half the key cap) dispatches; the tail's ``(rid, keys)`` pairs are
         handed back, still in submit order, to rejoin the queue. Only the
         tail can be held — earlier batches were closed by the cap, and
-        holding a middle batch would reorder admissions past FIFO. A
-        deadline trigger (or plain ``form``) flushes the held tail
-        eventually, so no request is starved.
+        holding a middle batch would reorder admissions past FIFO. The
+        caller decides how long a held tail waits:
+        :meth:`repro.service.SortService.flush_ready` holds it only while
+        the service has work on the device, and launches it once the
+        dispatcher is idle or at the ``flush_after_s`` deadline, so no
+        request is starved.
         """
         if min_keys is None:
             min_keys = self.max_batch_keys // 2

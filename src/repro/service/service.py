@@ -154,6 +154,7 @@ class _Pending:
     keys: np.ndarray
     future: SortFuture
     t_submit: float = 0.0  # tracer clock at submit (traced runs only)
+    held: bool = False  # an underfilled tail that flush_ready held
 
 
 class SortService:
@@ -250,7 +251,7 @@ class SortService:
 
     @property
     def flush_triggers(self) -> Dict[str, int]:
-        """trigger (manual/size/deadline/ready/claim) -> flush count."""
+        """trigger (manual/size/deadline/ready/idle/claim) -> flush count."""
         return {
             str(lbl["trigger"]): c.value
             for lbl, c in obs.metrics().collect(
@@ -458,10 +459,14 @@ class SortService:
         """Admission-aware launch for open-loop arrival pumps.
 
         Dispatches only batches that are full enough
-        (:meth:`BatchFormer.form_ready`); an underfilled tail batch stays
-        pending for more traffic — the deadline trigger or any plain
-        ``flush`` clears it, so nothing starves. Non-blocking; returns
-        whether any batch launched.
+        (:meth:`BatchFormer.form_ready`). An underfilled tail batch is held
+        for more traffic only while the service has work on the device: if
+        no full batch launched and the dispatcher is idle (nothing queued,
+        nothing in flight), the tail launches at once (trigger ``idle``).
+        A held tail is released by :meth:`run_pending` once the dispatcher
+        goes idle, or by the ``flush_after_s`` deadline or any plain
+        ``flush``, so nothing starves. Non-blocking; returns whether any
+        batch launched.
         """
         with self._locked("flush_ready"):
             self._expire_deadlines()
@@ -470,10 +475,16 @@ class SortService:
             batches, held = self.former.form_ready(
                 [(r.rid, r.keys) for r in todo], min_keys=min_keys
             )
+            trigger = "ready"
+            if not batches and held and self.dispatcher.idle:
+                # work-conserving: nothing runs that the tail could fuse behind
+                batches, held, trigger = self.former.form(held), [], "idle"
             if batches:
-                self._count_flush("ready")
+                self._count_flush(trigger)
+            for rid, _ in held:
+                by_rid[rid].held = True
             self._pending = [by_rid[rid] for rid, _ in held] + self._pending
-            self._enqueue(batches, by_rid, "ready")
+            self._enqueue(batches, by_rid, trigger)
             return bool(batches)
 
     def flush(self, trigger: str = "manual") -> Dict[int, RequestResult]:
@@ -575,15 +586,21 @@ class SortService:
         ``flush_after_s`` auto-flush if the oldest pending request is
         overdue — so a quiet service still flushes without anyone
         submitting or claiming — and lets the dispatcher launch
-        backoff-due batches and complete up to ``max_steps`` flights.
-        Callable from a thread (:meth:`start_driver`) or polled from an
-        event loop. Returns whether work remains.
+        backoff-due batches and complete up to ``max_steps`` flights. If
+        the dispatcher is then idle and :meth:`flush_ready` held a tail,
+        the tail launches (trigger ``idle``): it waited only for the
+        device work it could have fused behind. Callable from a thread
+        (:meth:`start_driver`) or polled from an event loop. Returns
+        whether work remains.
         """
         with self._locked("run_pending"):
             self._expire_deadlines()
             self.maybe_flush()
-            busy = self.dispatcher.run_pending(max_steps=max_steps)
-            return busy or bool(self._pending)
+            if not self.dispatcher.run_pending(max_steps=max_steps) and any(
+                r.held for r in self._pending
+            ):
+                self.flush_async(trigger="idle")
+            return not self.dispatcher.idle or bool(self._pending)
 
     def start_driver(self, interval_s: float = 0.002) -> None:
         """Run :meth:`run_pending` on a daemon thread every ``interval_s``.

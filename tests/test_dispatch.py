@@ -1,10 +1,13 @@
 """Async dispatch: futures, in-flight batches, failsink fault isolation,
 bounded unclaimed store, admission-aware forming, telemetry memoization."""
+import sys
+import threading
 import time
 
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import SortExecutor, sort_segments
 from repro.service import (
     BatchFormer,
@@ -19,9 +22,9 @@ pytestmark = pytest.mark.fast
 POISON_LEN = 777  # unique request length the poison monkeypatches key on
 
 
-def _arrays(sizes, seed=0):
+def _arrays(sizes, seed=0, span=2**31):  # a small span: many equal keys
     rng = np.random.default_rng(seed)
-    return [rng.integers(-(2**31), 2**31, s).astype(np.int32) for s in sizes]
+    return [rng.integers(-span, span, s).astype(np.int32) for s in sizes]
 
 
 def test_submit_returns_future_without_dispatching():
@@ -244,6 +247,155 @@ def test_form_ready_holds_partial_tail_and_flush_ready_launches_full():
     assert svc.pending == 0
     for a, f in zip(arrays, futs):
         assert np.array_equal(f.result().keys, np.sort(a))
+
+
+def _assert_stable_sorted(arrays, futs):
+    for a, f in zip(arrays, futs):
+        res = f.result()
+        assert np.array_equal(res.keys, np.sort(a))
+        assert np.array_equal(res.order, np.argsort(a, kind="stable"))
+
+
+def test_flush_ready_launches_underfilled_tail_when_dispatcher_idle():
+    """Work-conserving release: with nothing queued or in flight there is
+    no device work for the tail to fuse behind, so it launches at once."""
+    svc = SortService(
+        ServiceConfig(p=8, max_batch_keys=1 << 12, pair_capacity="exact"),
+        executor=SortExecutor(),
+    )
+    arrays = _arrays([300, 100, 250], seed=40, span=50)  # 650 keys < 2048
+    futs = [svc.submit(a) for a in arrays]
+    assert svc.dispatcher.idle
+    assert svc.flush_ready()  # underfilled, yet launched
+    assert svc.pending == 0 and svc.dispatcher.in_flight == 1
+    assert svc.flush_triggers == {"idle": 1}
+    _assert_stable_sorted(arrays, futs)
+
+
+def test_held_tail_launches_when_run_pending_lands_the_flight():
+    """While a flight is outstanding flush_ready holds the tail; the
+    driver pump that completes the flight launches it, long before the
+    flush_after_s deadline."""
+    svc = SortService(
+        ServiceConfig(
+            p=8, max_batch_keys=1 << 12, pair_capacity="exact",
+            flush_after_s=60.0,
+        ),
+        executor=SortExecutor(),
+    )
+    arrays = _arrays([400, 120], seed=41, span=50)
+    t0 = time.perf_counter()
+    first = svc.submit(arrays[0])
+    assert svc.flush_ready()  # idle: launches alone
+    second = svc.submit(arrays[1])
+    assert not svc.flush_ready()  # a flight is outstanding: held
+    assert svc.pending == 1 and not second.done()
+    assert svc.run_pending(max_steps=1)  # lands the flight, launches the tail
+    assert first.done() and svc.pending == 0
+    assert svc.dispatcher.in_flight == 1
+    assert svc.flush_triggers == {"idle": 2}
+    _assert_stable_sorted(arrays, [first, second])
+    assert time.perf_counter() - t0 < 30.0  # not the 60 s deadline
+
+
+def test_arrivals_during_a_flight_fuse_into_one_idle_batch():
+    t = obs.Tracer()
+    svc = SortService(
+        ServiceConfig(
+            p=8, max_batch_keys=1 << 12, pair_capacity="exact",
+            flush_after_s=60.0, obs=t,
+        ),
+        executor=SortExecutor(),
+    )
+    arrays = _arrays([500, 90, 210, 130], seed=42, span=50)
+    futs = [svc.submit(arrays[0])]
+    assert svc.flush_ready()
+    for a in arrays[1:]:  # each arrival meets the outstanding flight
+        futs.append(svc.submit(a))
+        assert not svc.flush_ready()
+    assert svc.pending == 3
+    svc.run_pending(max_steps=1)
+    assert svc.flush_triggers == {"idle": 2}
+    svc.dispatcher.drain()
+    queued = [s["args"]["n_rids"] for s in t.spans if s["name"] == "queue"]
+    assert queued == [1, 3]  # the three held arrivals rode one batch
+    pending = [s for s in t.spans if s["name"] == "pending"]
+    assert {s["args"]["trigger"] for s in pending} == {"idle"}
+    _assert_stable_sorted(arrays, futs)
+
+
+def test_deadline_only_service_under_driver_never_reads_idle():
+    """The release applies only to tails flush_ready held: plain submits
+    under the driver thread still wait for flush_after_s."""
+    t = obs.Tracer()
+    svc = SortService(
+        ServiceConfig(
+            p=8, max_batch_keys=1 << 12, pair_capacity="exact",
+            flush_after_s=0.05, obs=t,
+        ),
+        executor=SortExecutor(),
+    )
+    arrays = _arrays([200, 70, 330], seed=43, span=50)
+    def wait_done(futs):  # without claiming: a claim flushes by itself
+        t_end = time.perf_counter() + 60.0
+        while not all(f.done() for f in futs):
+            assert time.perf_counter() < t_end, "futures did not resolve"
+            time.sleep(0.002)
+
+    svc.start_driver(interval_s=0.002)
+    try:
+        futs = [svc.submit(arrays[0])]
+        wait_done(futs)  # a flight came and went: the dispatcher is idle
+        futs += [svc.submit(a) for a in arrays[1:]]
+        wait_done(futs)
+    finally:
+        svc.stop_driver()
+    assert set(svc.flush_triggers) == {"deadline"}
+    pending = [s for s in t.spans if s["name"] == "pending"]
+    assert len(pending) == 3
+    assert {s["args"]["trigger"] for s in pending} == {"deadline"}
+    assert all(s["dur"] >= 0.05 for s in pending)
+    _assert_stable_sorted(arrays, futs)
+
+
+def test_idle_release_strands_no_tail_under_concurrent_submitters():
+    """Stress: submitter threads race the driver thread for the service
+    lock. With no deadline at all, only the idle release can launch a held
+    tail, so a tail stranded while the dispatcher idles would hang here."""
+    svc = SortService(
+        ServiceConfig(p=8, max_batch_keys=256, pair_capacity="exact"),
+        executor=SortExecutor(),
+    )
+    per_thread, n_threads = 25, 6
+    arrays = _arrays([100 + i % 21 for i in range(per_thread * n_threads)], seed=44, span=50)
+    futs = [None] * len(arrays)
+
+    def submitter(t: int) -> None:
+        for i in range(t, len(arrays), n_threads):
+            futs[i] = svc.submit(arrays[i])
+            svc.flush_ready()  # 100-120 keys: alone a tail, a pair is ready
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    svc.start_driver(interval_s=0.001)
+    try:
+        threads = [threading.Thread(target=submitter, args=(t,)) for t in range(n_threads)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=120.0)
+            assert not th.is_alive()
+        t_end = time.perf_counter() + 120.0
+        while not all(f.done() for f in futs):
+            assert time.perf_counter() < t_end, "a held tail was stranded"
+            time.sleep(0.005)
+    finally:
+        svc.stop_driver()
+        sys.setswitchinterval(interval)
+    assert svc.pending == 0 and svc.dispatcher.idle
+    assert svc.requests_done == len(arrays)
+    assert set(svc.flush_triggers) <= {"ready", "idle"}
+    _assert_stable_sorted(arrays, futs)
 
 
 def test_two_poison_requests_in_one_batch_both_isolated(monkeypatch):
